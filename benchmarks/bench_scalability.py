@@ -17,9 +17,11 @@ fast stack with eigh, (b) single-device with Newton–Schulz (the measured
 eigh↔NS crossover data), and (c) the edge-partitioned ``core.shard`` path
 across ``--partition-devices`` devices, then emits a compare row with the
 sharded-vs-single speedup and the best-candidate ``r_asym`` parity drift.
-If the current process has fewer devices it re-execs itself in a subprocess
+On an accelerator it runs in this process over the attached devices (a chip
+belongs to one process, so a child could not reach it). Only on the CPU
+backend with too few devices does it re-exec itself in a CPU-only subprocess
 with ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the flag must
-precede the first jax init, which importing this module already did).
+precede the first backend initialization).
 
   PYTHONPATH=src python -m benchmarks.bench_scalability --nodes 4,8,16,32,64
   PYTHONPATH=src python -m benchmarks.bench_scalability --nodes "" \
@@ -291,11 +293,13 @@ def _partition_compare_subprocess(nodes: list[int], admm_iters: int,
     """Re-exec this benchmark with N simulated host devices.
 
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` only takes effect
-    before the first jax initialization, which importing this module already
-    triggered — so the multi-device run needs a fresh interpreter.
+    before the first backend initialization, so the simulated-device run
+    needs a fresh interpreter. Only called when this process runs on the
+    CPU backend (see ``main``), so the child never competes for a chip.
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     with tempfile.TemporaryDirectory() as td:
         out = os.path.join(td, "partition.json")
@@ -357,15 +361,22 @@ def main(argv=None) -> None:
         ndev = args.partition_devices
         import jax
 
-        if jax.device_count() >= ndev:
-            print(f"== sharded-ADMM partition compare ({ndev} devices) ==")
-            rows += run_partition_compare(pnodes, args.partition_iters,
-                                          args.seed, ndev)
-        else:
+        # a chip belongs to one process, so on an accelerator the compare
+        # runs here over the attached devices; the CPU backend is not
+        # exclusive, so there a child with simulated devices is safe
+        if jax.default_backend() == "cpu" and jax.device_count() < ndev:
             print(f"== sharded-ADMM partition compare "
                   f"(subprocess, {ndev} simulated devices) ==")
             rows += _partition_compare_subprocess(pnodes, args.partition_iters,
                                                   args.seed, ndev)
+        else:
+            if jax.device_count() < ndev:
+                raise SystemExit(
+                    f"--partition-devices {ndev} but {jax.device_count()} "
+                    f"{jax.default_backend()} device(s) are attached")
+            print(f"== sharded-ADMM partition compare ({ndev} devices) ==")
+            rows += run_partition_compare(pnodes, args.partition_iters,
+                                          args.seed, ndev)
 
     if args.json_out:
         with open(args.json_out, "w") as f:

@@ -40,6 +40,8 @@ def main(argv=None) -> None:
                          "refreshing the committed baseline, while CI gates "
                          "a dedicated n=512 smoke subset)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     os.makedirs(ART, exist_ok=True)
     quick = not args.full
 

@@ -43,10 +43,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..kernels.edge_laplacian import ops as _el_ops
+from ..launch.mesh import make_mesh
 from . import engine
 from .engine import (
     FP32_TOL_FLOOR, INEXACT_CAP, INEXACT_ETA,
@@ -149,12 +149,12 @@ def _data_keys(hetero: bool, precond: str):
 
 @lru_cache(maxsize=None)
 def _edge_mesh(ndev: int):
-    return jax.make_mesh((ndev,), (_AXIS,))
+    return make_mesh((ndev,), (_AXIS,))
 
 
 @lru_cache(maxsize=None)
 def _instance_mesh(ndev: int):
-    return jax.make_mesh((ndev,), (_INST_AXIS,))
+    return make_mesh((ndev,), (_INST_AXIS,))
 
 
 @lru_cache(maxsize=None)
@@ -397,12 +397,12 @@ def _get_runner(meta: tuple):
     ed_keys, rd_keys = _data_keys(hetero, precond)
     sspec = _state_specs(hetero)
     mesh = _edge_mesh(ndev)
-    f = shard_map(
+    f = jax.shard_map(
         run, mesh=mesh,
         in_specs=({k: P(_AXIS) for k in ed_keys}, {k: P() for k in rd_keys},
                   sspec),
         out_specs=(sspec, P(), P(), (P(), P(), P())),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(f)
 
 

@@ -3,7 +3,8 @@
 Two pipelines:
 
 1. ``token_pipeline`` — language-model batches {tokens, labels} with a
-   *learnable* structure (a hidden bigram Markov chain) so training loss
+   *learnable* structure (a hidden sparse bigram Markov chain: every token
+   has ``SUCCESSORS`` likely successors) so training loss
    demonstrably decreases; used by the end-to-end DSGD example and the
    per-arch smoke tests. VLM/audio archs additionally get stub ``embeds``
    (the brief's frontend carve-out).
@@ -17,6 +18,7 @@ Two pipelines:
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import lru_cache
 
 import numpy as np
 
@@ -37,16 +39,25 @@ class DataConfig:
     seed: int = 0
 
 
-def _bigram_table(vocab: int, seed: int) -> np.ndarray:
-    """Row-stochastic bigram transition table with low entropy (learnable)."""
+#: Successors per token in the hidden bigram chain.
+SUCCESSORS = 4
+
+
+@lru_cache(maxsize=8)
+def _successor_table(vocab: int, seed: int) -> tuple[np.ndarray, np.ndarray]:
+    """The hidden chain as ``(succ (vocab, k) int32, cdf (vocab, k))``:
+    token v is followed by ``succ[v, j]`` with probability
+    ``cdf[v, j] − cdf[v, j−1]`` (softmax of N(0, 2²) logits, low entropy so
+    the chain is learnable). O(vocab·k) to build and hold, so it exists at
+    any published vocabulary size; cached per ``(vocab, seed)``."""
     rng = np.random.default_rng(seed)
-    logits = rng.normal(size=(vocab, vocab)) * 2.0
-    # sparsify: each token strongly predicts ~4 successors
-    top = np.argpartition(-logits, 4, axis=1)[:, :4]
-    mask = np.full_like(logits, -1e9)
-    np.put_along_axis(mask, top, 0.0, axis=1)
-    p = np.exp(logits + mask)
-    return p / p.sum(axis=1, keepdims=True)
+    succ = rng.integers(0, vocab, size=(vocab, SUCCESSORS)).astype(np.int32)
+    p = np.exp(rng.normal(size=(vocab, SUCCESSORS)) * 2.0)
+    cdf = np.cumsum(p / p.sum(axis=1, keepdims=True), axis=1)
+    cdf[:, -1] = 1.0
+    succ.setflags(write=False)
+    cdf.setflags(write=False)
+    return succ, cdf
 
 
 def synthetic_lm_batch(cfg: DataConfig, step: int, node: int = 0) -> dict:
@@ -54,14 +65,16 @@ def synthetic_lm_batch(cfg: DataConfig, step: int, node: int = 0) -> dict:
     so every DSGD worker regenerates its own shard without host state."""
     rng = np.random.default_rng(
         np.random.SeedSequence([cfg.seed, node, step]))
-    table = _bigram_table(cfg.vocab_size, cfg.seed)
+    succ, cdf = _successor_table(cfg.vocab_size, cfg.seed)
     B, S = cfg.batch_size, cfg.seq_len
     toks = np.empty((B, S), dtype=np.int32)
     toks[:, 0] = rng.integers(0, cfg.vocab_size, size=B)
     u = rng.random((B, S))
-    cdf = np.cumsum(table, axis=1)
     for t in range(1, S):
-        toks[:, t] = np.argmax(cdf[toks[:, t - 1]] > u[:, t, None], axis=1)
+        prev = toks[:, t - 1]
+        j = np.minimum((cdf[prev] <= u[:, t, None]).sum(axis=1),
+                       SUCCESSORS - 1)
+        toks[:, t] = succ[prev, j]
     batch = {
         "tokens": jnp.asarray(toks),
         "labels": jnp.asarray(np.concatenate(

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..mode import pallas_call
+
 BLOCK_K = 512
 
 
@@ -69,7 +71,7 @@ def _decode_attn_kernel(q_ref, k_ref, v_ref, valid_ref, out_ref, m_ref, l_ref, a
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
 def decode_attention_kernel(q, k, v, valid, *, softcap: float = 0.0,
-                            interpret: bool = True):
+                            interpret: bool | None = None):
     """q: (B, Hq, hd); k/v: (B, C, Hkv, hd); valid: (C,) bool/int32.
 
     Returns (B, Hq, hd). C must be a multiple of BLOCK_K (ops.py pads)."""
@@ -83,7 +85,7 @@ def decode_attention_kernel(q, k, v, valid, *, softcap: float = 0.0,
     valid2 = valid.astype(jnp.int32).reshape(1, C)
     scale = 1.0 / float(hd) ** 0.5
 
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_decode_attn_kernel, softcap=softcap, scale=scale),
         grid=(B, Hkv, C // BLOCK_K),
         in_specs=[

@@ -13,7 +13,7 @@ from .kernel import BLOCK_K, decode_attention_kernel
 
 @functools.partial(jax.jit, static_argnames=("attn_softcap", "use_kernel", "interpret"))
 def decode_attention(q, k, v, valid, *, attn_softcap: float = 0.0,
-                     use_kernel: bool = True, interpret: bool = True):
+                     use_kernel: bool = True, interpret: bool | None = None):
     """q: (B, Hq, hd); k/v: (B, C, Hkv, hd); valid: (C,) — see ref.py."""
     if not use_kernel:
         return ref.decode_attention(q, k, v, valid, attn_softcap=attn_softcap)

@@ -26,9 +26,14 @@ TPU adaptation notes (mirroring ``gossip_mix``):
     wrappers in ``ops.py`` pad n and m up and slice the result back.
   - P / the L row-band stay whole in VMEM: n ≤ ~1500 keeps n² f32 within
     the ~16 MB budget, far above the paper's regime.
-  - the per-tile dynamic gathers lower through Mosaic's gather support on
-    recent toolchains; ``interpret=True`` (the repo default on CPU) is the
-    reference execution mode, as for the other kernels in this tree.
+  - both kernels gather with a 2-D index tile from a 1-D vector (L) or
+    from a VMEM-resident matrix (quadform). Mosaic refuses both ("Only 2D
+    gather is supported"; the quadform gather fails its lowering asserts),
+    so neither compiles for the TPU: with ``interpret`` resolved to False
+    they raise ``kernels.mode.TPUUnsupportedError`` instead of running the
+    interpreter or the pure-JAX path without a word. They run in interpret
+    mode off the TPU; ``ADMMConfig.edge_kernel=False`` (the default) keeps
+    the engine on the XLA gather/scatter path everywhere.
 """
 from __future__ import annotations
 
@@ -38,8 +43,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..mode import interpret_only, pallas_call
+
 LANE = 128     # last-dim tile (multiple of 128)
 SUBLANE = 8    # second-to-last dim tile
+
+_NO_TPU = ("Mosaic refuses its in-kernel dynamic gather; run the ADMM with "
+           "ADMMConfig(edge_kernel=False) on the TPU")
 
 
 def _edge_laplacian_kernel(n, g_ref, out_ref):
@@ -59,13 +69,14 @@ def _edge_laplacian_kernel(n, g_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret"))
-def edge_laplacian_2d(g, n: int, *, interpret: bool = True):
+def edge_laplacian_2d(g, n: int, *, interpret: bool | None = None):
     """g: (m_pad,) packed complete-graph edge weights; returns L (r_pad, c_pad)
     with r_pad = ceil(n/SUBLANE)·SUBLANE, c_pad = ceil(n/LANE)·LANE."""
     r_pad = -(-n // SUBLANE) * SUBLANE
     c_pad = -(-n // LANE) * LANE
     m_pad = g.shape[0]
-    return pl.pallas_call(
+    interpret = interpret_only("edge_laplacian", interpret, _NO_TPU)
+    return pallas_call(
         functools.partial(_edge_laplacian_kernel, n),
         grid=(r_pad // SUBLANE,),
         in_specs=[pl.BlockSpec((m_pad,), lambda i: (0,))],
@@ -84,13 +95,14 @@ def _edge_quadform_kernel(P_ref, ei_ref, ej_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def edge_quadform_2d(P, ei, ej, *, interpret: bool = True):
+def edge_quadform_2d(P, ei, ej, *, interpret: bool | None = None):
     """P: (n_pad, n_pad); ei/ej: (R, LANE) int32 edge endpoints (R % SUBLANE
     == 0, padding entries 0 — they read P[0,0] terms that cancel to 0)."""
     R, L = ei.shape
     assert L == LANE and R % SUBLANE == 0, (R, L)
     nr, nc = P.shape
-    return pl.pallas_call(
+    interpret = interpret_only("edge_quadform", interpret, _NO_TPU)
+    return pallas_call(
         _edge_quadform_kernel,
         grid=(R // SUBLANE,),
         in_specs=[

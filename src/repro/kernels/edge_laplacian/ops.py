@@ -19,7 +19,7 @@ def _pad_to(x, size):
 
 @functools.partial(jax.jit, static_argnames=("n", "use_kernel", "interpret"))
 def edge_laplacian(g, ei, ej, n: int, *, use_kernel: bool = True,
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """Laplacian L(g) of the complete candidate-edge list.
 
     g: (m,) edge weights in ``all_edges(n)`` (lexicographic) order; ei/ej:
@@ -50,7 +50,7 @@ def edge_laplacian_window(g_loc, lidx, offset):
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def edge_quadform(P, ei, ej, *, use_kernel: bool = True,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """Per-edge quadratic forms ⟨∂L/∂g_l, P⟩ = P_ii + P_jj − P_ij − P_ji.
 
     P: (n, n); ei/ej: (m,) edge endpoints (any edge list — the gather is

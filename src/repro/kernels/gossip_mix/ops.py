@@ -8,13 +8,13 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .kernel import LANE, SUBLANE, gossip_mix_2d, gossip_mix_batched_2d
-
-_TILE = LANE * SUBLANE
+from .kernel import (LANE, block_rows, gossip_mix_2d, gossip_mix_batched_2d,
+                     padded_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def gossip_mix(x, nbrs, weights, *, use_kernel: bool = True, interpret: bool = True):
+def gossip_mix(x, nbrs, weights, *, use_kernel: bool = True,
+               interpret: bool | None = None):
     """Mix one worker's parameter tensor with its neighbors' copies.
 
     x: (...,) any shape; nbrs: (deg, ...) stacked neighbor copies;
@@ -26,10 +26,10 @@ def gossip_mix(x, nbrs, weights, *, use_kernel: bool = True, interpret: bool = T
     deg = nbrs.shape[0]
     flat = x.reshape(-1)
     n = flat.shape[0]
-    pad = (-n) % _TILE
+    R = padded_rows(n, block_rows(deg, x.dtype.itemsize))
+    pad = R * LANE - n
     flat = jnp.pad(flat, (0, pad))
     nflat = jnp.pad(nbrs.reshape(deg, -1), ((0, 0), (0, pad)))
-    R = flat.shape[0] // LANE
     out = gossip_mix_2d(flat.reshape(R, LANE), nflat.reshape(deg, R, LANE),
                         weights, interpret=interpret)
     return out.reshape(-1)[:n].reshape(shape)
@@ -37,7 +37,7 @@ def gossip_mix(x, nbrs, weights, *, use_kernel: bool = True, interpret: bool = T
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def gossip_mix_batched(x, nbr_idx, weights, *, use_kernel: bool = True,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """Mix ALL workers' copies of one parameter tensor in a single dispatch.
 
     x: (n, ...) stacked worker copies; nbr_idx: (n, deg) int32 padded
@@ -57,9 +57,8 @@ def gossip_mix_batched(x, nbr_idx, weights, *, use_kernel: bool = True,
     shape = x.shape
     flat = x.reshape(n, -1)
     m = flat.shape[1]
-    pad = (-m) % _TILE
-    flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    R = flat.shape[1] // LANE
+    R = padded_rows(m, block_rows(nbr_idx.shape[1], x.dtype.itemsize))
+    flat = jnp.pad(flat, ((0, 0), (0, R * LANE - m)))
     xr = flat.reshape(n, R, LANE)
     nbrs = xr[nbr_idx]                       # (n, deg, R, LANE), one gather
     out = gossip_mix_batched_2d(xr, nbrs, weights, interpret=interpret)
@@ -67,7 +66,7 @@ def gossip_mix_batched(x, nbr_idx, weights, *, use_kernel: bool = True,
 
 
 def gossip_mix_tree(params, nbr_params, weights, *, use_kernel: bool = True,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """Apply gossip_mix leaf-wise over a parameter pytree.
 
     params: pytree of arrays; nbr_params: same pytree with a leading (deg,)

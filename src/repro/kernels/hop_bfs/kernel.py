@@ -21,10 +21,10 @@ TPU adaptation notes (mirroring ``edge_laplacian``/``gossip_mix``):
     of 8); wrappers in ``ops.py`` pad n up and slice the result back.
     Padded rows/columns are all-zero, so they contribute nothing to the
     matmul, the OR, or the counts.
-  - Adj stays whole in VMEM: n ≤ ~1500 keeps n² f32 within the ~16 MB
-    budget, far above the paper's regime.
-  - ``interpret=True`` (the repo default on CPU) is the reference
-    execution mode, as for the other kernels in this tree.
+  - Adj stays whole in VMEM (n_pad² f32, double-buffered); the v5e
+    compiler accepts it through n = 2048 (ahead-of-time compile tests),
+    far above the paper's regime.
+  - ``interpret=None`` compiles on the TPU and interprets elsewhere.
 """
 from __future__ import annotations
 
@@ -33,6 +33,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..mode import pallas_call
 
 LANE = 128     # last-dim tile (multiple of 128)
 SUBLANE = 8    # second-to-last dim tile
@@ -51,7 +53,7 @@ def _hop_step_kernel(reach_ref, adj_ref, out_ref, cnt_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def hop_step_2d(reach, adj, *, interpret: bool = True):
+def hop_step_2d(reach, adj, *, interpret: bool | None = None):
     """reach: (r_pad, c_pad) f32 0/1 with r_pad % SUBLANE == 0 and
     c_pad % LANE == 0; adj: (c_pad, c_pad) f32 0/1 (symmetric, zero
     padding). Returns ``(new_reach (r_pad, c_pad), counts (r_pad, LANE))``
@@ -59,7 +61,7 @@ def hop_step_2d(reach, adj, *, interpret: bool = True):
     r_pad, c_pad = reach.shape
     assert r_pad % SUBLANE == 0 and c_pad % LANE == 0, (r_pad, c_pad)
     assert adj.shape == (c_pad, c_pad), (adj.shape, c_pad)
-    return pl.pallas_call(
+    return pallas_call(
         _hop_step_kernel,
         grid=(r_pad // SUBLANE,),
         in_specs=[

@@ -12,7 +12,8 @@ from .kernel import LANE, SUBLANE, hop_step_2d
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def hop_step(reach, adj, *, use_kernel: bool = True, interpret: bool = True):
+def hop_step(reach, adj, *, use_kernel: bool = True,
+             interpret: bool | None = None):
     """One matmul-BFS hop: ``new = reach ∨ (reach @ Adj)`` plus the total
     reached-pair count of ``new``.
 

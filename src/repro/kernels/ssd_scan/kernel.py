@@ -19,6 +19,9 @@ TPU adaptation notes (vs the paper's Triton kernel):
     non-increasing, so no extra max-subtraction is needed.
   - B/C blocks are shared across heads (G=1 groups): the (b, c, :) BlockSpec
     re-streams them per head, trading a little DMA for zero layout shuffles.
+  - not yet compiled for the TPU: the Pallas TPU lowering refuses the
+    (1, 1, Q, 1, P) x block (second-minor block dim 1 against H heads), so
+    off the interpreter the call raises ``TPUUnsupportedError``.
 """
 from __future__ import annotations
 
@@ -27,6 +30,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..mode import interpret_only, pallas_call
 
 
 def _ssd_intra_kernel(x_ref, dt_ref, la_ref, b_ref, c_ref, y_ref, st_ref):
@@ -57,7 +62,7 @@ def _ssd_intra_kernel(x_ref, dt_ref, la_ref, b_ref, c_ref, y_ref, st_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_intra_chunk_kernel(xc, dtc, la, Bc, Cc, *, interpret: bool = True):
+def ssd_intra_chunk_kernel(xc, dtc, la, Bc, Cc, *, interpret: bool | None = None):
     """xc: (B,nc,Q,H,P); dtc/la: (B,nc,Q,H); Bc/Cc: (B,nc,Q,N).
 
     Returns (y_intra (B,nc,Q,H,P) f32, chunk_states (B,nc,H,P,N) f32)."""
@@ -70,7 +75,7 @@ def ssd_intra_chunk_kernel(xc, dtc, la, Bc, Cc, *, interpret: bool = True):
     Bg = Bc.reshape(Bsz * nc, Q, N)[:, None]
     Cg = Cc.reshape(Bsz * nc, Q, N)[:, None]
 
-    y, st = pl.pallas_call(
+    y, st = pallas_call(
         _ssd_intra_kernel,
         grid=grid,
         in_specs=[
@@ -88,6 +93,10 @@ def ssd_intra_chunk_kernel(xc, dtc, la, Bc, Cc, *, interpret: bool = True):
             jax.ShapeDtypeStruct((Bsz * nc, 1, Q, H, P), jnp.float32),
             jax.ShapeDtypeStruct((Bsz * nc, 1, H, P, N), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_only(
+            "ssd_intra_chunk", interpret,
+            "its (1, 1, Q, 1, P) blocks put a size-1 block on the head axis, "
+            "which the TPU lowering refuses; use_kernel=False runs the "
+            "pure-JAX dual form"),
     )(xg, dtg, lag, Bg, Cg)
     return (y.reshape(Bsz, nc, Q, H, P), st.reshape(Bsz, nc, H, P, N))

@@ -24,7 +24,7 @@ import numpy as np
 import jax
 
 from repro.configs import ARCHS, INPUT_SHAPES, get_arch, shape_supported
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.steps import build_step
 from repro.launch.sharding import params_bytes
 from repro.models import transformer
@@ -158,9 +158,8 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.mesh_shape:
-        import jax as _jax
         d, m = (int(x) for x in args.mesh_shape.split("x"))
-        mesh = _jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
         mesh_name = f"{d}x{m}"
     else:
         mesh = make_production_mesh(multi_pod=args.multi_pod)

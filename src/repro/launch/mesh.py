@@ -17,8 +17,10 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_host_mesh", "POD_SHAPE", "MULTIPOD_SHAPE"]
+__all__ = ["make_mesh", "make_production_mesh", "make_host_mesh", "POD_SHAPE",
+           "MULTIPOD_SHAPE"]
 
 POD_SHAPE = (16, 16)
 MULTIPOD_SHAPE = (2, 16, 16)
@@ -40,14 +42,25 @@ def _check_devices(shape: tuple[int, ...], axes: tuple[str, ...]) -> None:
             "tests/test_sharded_runtime.py does).")
 
 
+def make_mesh(shape, axes):
+    """The repo's one mesh constructor: every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which every op
+    must agree on its operands' sharding types; the solver and the model
+    code are written for GSPMD propagation (``Auto``), and inside
+    ``shard_map`` the mapped axes become ``Manual`` either way."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTIPOD_SHAPE if multi_pod else POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     _check_devices(shape, axes)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (CPU tests / examples)."""
     _check_devices((data, model), ("data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -5,14 +5,13 @@ NamedShardings attached — no device allocation — so ``jax.jit(fn).lower(*arg
 compiles the production program exactly as it would run on the target mesh.
 
 Topology selection: the gossip topology for n workers is BA-Topo by default
-(the paper's contribution, solved by the ADMM core and cached on disk), with
+(the paper's contribution, solved by the ADMM core in this process and
+memoized for its lifetime — never read from disk), with
 baseline topologies (ring / exponential / u_equistatic) and the centralized
 all-reduce selectable for comparisons — the knobs the §Perf hillclimb turns.
 """
 from __future__ import annotations
 
-import json
-import os
 from dataclasses import dataclass
 from typing import Callable
 
@@ -47,10 +46,7 @@ from .sharding import (
     with_sharding,
 )
 
-__all__ = ["BuiltStep", "build_step", "input_specs", "topology_for", "TOPO_CACHE"]
-
-TOPO_CACHE = os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                          "benchmarks", "artifacts", "topo_cache.json")
+__all__ = ["BuiltStep", "build_step", "input_specs", "topology_for"]
 
 
 @dataclass
@@ -99,7 +95,7 @@ def _with_rules(fn: Callable, rules: dict) -> Callable:
 
 
 # ---------------------------------------------------------------------------
-# topology cache (the ADMM solve is host-side; reuse across dry-run combos)
+# topology memo (one solve per (n, kind, r, seed, bandwidths) per process)
 # ---------------------------------------------------------------------------
 
 _MEM_CACHE: dict[tuple, Topology] = {}
@@ -128,7 +124,7 @@ def topology_for(n: int, kind: str = "ba", r: int | None = None,
     elif n == 2:
         topo = Topology(2, [(0, 1)], np.array([0.5]), name="pair")
     elif kind == "ba":
-        topo = _cached_ba_topology(n, r, seed, node_bw)
+        topo = _solve_ba_topology(n, r, seed, node_bw)
     elif kind == "random":
         topo = make_baseline(kind, n, r=r, seed=seed)
     else:
@@ -137,34 +133,14 @@ def topology_for(n: int, kind: str = "ba", r: int | None = None,
     return topo
 
 
-def _cached_ba_topology(n: int, r: int, seed: int,
-                        node_bw: "list[float] | None" = None) -> Topology:
-    path = os.path.abspath(TOPO_CACHE)
-    cache = {}
-    if os.path.exists(path):
-        with open(path) as f:
-            cache = json.load(f)
-    ck = f"n{n}_r{r}_s{seed}"
-    if node_bw is not None:
-        ck += "_bw" + ",".join(f"{b:g}" for b in node_bw)
-    if ck in cache:
-        d = cache[ck]
-        return Topology(n, [tuple(e) for e in d["edges"]], np.asarray(d["g"]),
-                        name=f"ba-topo(n={n},r={r})", meta=d.get("meta", {}))
+def _solve_ba_topology(n: int, r: int, seed: int,
+                       node_bw: "list[float] | None" = None) -> Topology:
     if node_bw is not None:
         req = TopologyRequest(n=n, r=r, scenario="node",
                               node_bandwidths=np.asarray(node_bw, float))
     else:
         req = TopologyRequest(n=n, r=r, scenario="homo")
-    topo = solve_topology(req, cfg=BATopoConfig(seed=seed)).topology
-    cache[ck] = {"edges": [list(e) for e in topo.edges],
-                 "g": np.asarray(topo.g).tolist(),
-                 "meta": {k: v for k, v in topo.meta.items()
-                          if isinstance(v, (int, float, str, bool, list))}}
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    with open(path, "w") as f:
-        json.dump(cache, f)
-    return topo
+    return solve_topology(req, cfg=BATopoConfig(seed=seed)).topology
 
 
 # ---------------------------------------------------------------------------

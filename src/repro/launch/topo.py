@@ -24,6 +24,7 @@ from repro.core import (
 )
 from repro.core.bandwidth import homo_edge_bandwidth, min_edge_bandwidth, t_iter
 from repro.core.graph import weight_matrix_from_weights
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def parse_bandwidths(spec: str, n: int) -> np.ndarray:
@@ -59,6 +60,7 @@ def main() -> None:
                          "deterministic solve")
     ap.add_argument("--out", default=None, help="write topology json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = BATopoConfig(sa_iters=args.sa_iters, seed=args.seed)
     n = args.n

@@ -57,6 +57,7 @@ from repro.dsgd import (
 )
 from repro.dsgd.dynamic import cycle_weight_matrices, round_robin_schedules
 from repro.dsgd.trainer import _consensus_error, _loss_fn
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import topology_for
 from repro.optim import apply_updates, make_optimizer, warmup_cosine
 
@@ -98,7 +99,10 @@ def _dynamic_step(cfg, topo, opt_update):
     return _dyn_step, len(Ws)
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict:
+    """Run the training loop; returns the run record (also written to
+    ``--json-out``): config, topology, per-logged-step metrics with the
+    step's wall time ``step_s``, and the elastic trail."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -118,7 +122,8 @@ def main() -> None:
     ap.add_argument("--optimizer", default="sgd", choices=["sgd", "adamw"])
     ap.add_argument("--lr", type=float, default=0.05)
     ap.add_argument("--use-kernel", action="store_true",
-                    help="fused Pallas gossip_mix (interpret mode on CPU)")
+                    help="fused Pallas gossip_mix (compiled on the TPU, "
+                         "interpreted elsewhere)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
@@ -147,7 +152,8 @@ def main() -> None:
     ap.add_argument("--kill-at-step", type=int, default=-1,
                     help="(testing) SIGKILL this process before running the "
                          "given step — simulates a crash mid-run")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -225,6 +231,7 @@ def main() -> None:
     t0 = time.time()
     modeled_ms = 0.0
     for s in range(start, args.steps):
+        t_step = time.perf_counter()
         if s == args.kill_at_step:
             os.kill(os.getpid(), signal.SIGKILL)     # crash, not cleanup
         data_step = es.data_step if args.elastic else s
@@ -243,25 +250,27 @@ def main() -> None:
             modeled_ms += iter_time * 1e3
         if s % args.log_every == 0 or s == args.steps - 1:
             m = {k: float(v) for k, v in metrics.items()}
-            m.update(step=s, wall_s=round(time.time() - t0, 1),
+            m.update(step=s, step_s=time.perf_counter() - t_step,
+                     wall_s=round(time.time() - t0, 1),
                      modelled_time_s=round(modeled_ms / 1e3, 4))
             history.append(m)
             print("  " + json.dumps(m))
         if s and s % args.ckpt_every == 0:
             save(int(state.step))
     save(int(state.step) if args.steps > start else args.steps)
+    out = {"config": vars(args), "topology": topo.name,
+           "r_asym": topo.r_asym() if len(topo.edges) else None,
+           "history": history}
+    if args.elastic:
+        out["elastic"] = {"events": es.events, "log": elastic_log,
+                          "reopts": es.reopts, "adopted": es.adopted,
+                          "drops": es.drops,
+                          "final_topology": es.topology.name}
     if args.json_out:
-        out = {"config": vars(args), "topology": topo.name,
-               "r_asym": topo.r_asym() if len(topo.edges) else None,
-               "history": history}
-        if args.elastic:
-            out["elastic"] = {"events": es.events, "log": elastic_log,
-                              "reopts": es.reopts, "adopted": es.adopted,
-                              "drops": es.drops,
-                              "final_topology": es.topology.name}
         with open(args.json_out, "w") as f:
             json.dump(out, f, indent=1)
         print(f"wrote {args.json_out}")
+    return out
 
 
 if __name__ == "__main__":

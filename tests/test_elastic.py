@@ -380,9 +380,10 @@ from repro.core import make_baseline
 from repro.core.graph import weight_matrix_from_weights
 from repro.dsgd import (degrade_matrix, gossip_shard_elastic, gossip_sim,
                         schedule_from_topology, schedule_weight_arrays)
+from repro.launch.mesh import make_mesh
 
 n = 8
-mesh = jax.make_mesh((n,), ("data",))
+mesh = make_mesh((n,), ("data",))
 topo = make_baseline("exponential", n)
 sched = schedule_from_topology(topo)
 W = jnp.asarray(weight_matrix_from_weights(n, topo.edges, topo.g), jnp.float32)
@@ -454,9 +455,6 @@ print("ELASTIC_SHARD_FREEZE_OK")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not (hasattr(jax, "set_mesh") and hasattr(jax, "shard_map")),
-    reason="requires the jax>=0.6 top-level set_mesh/shard_map APIs")
 def test_elastic_sharded_path():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)

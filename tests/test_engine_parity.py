@@ -112,9 +112,15 @@ def test_batched_solve_hetero():
 
 def test_sweep_over_budgets():
     """One vmapped call solves instances with different cardinality budgets
-    (r is a data leaf, not a static top-k arg)."""
+    (r is a data leaf, not a static top-k arg). The iteration is nonconvex,
+    so the sweep and the single solve are compared once both trajectories
+    have settled: λ̃ and the support's weights, not a mid-trajectory
+    iterate (at 60 iterations the two still differ by ~4e-3 in λ̃; at 1500
+    they agree to ~1e-11). At r=10 the two pick different edges of one
+    tied pair (equal weight, equal λ̃), so the weights are compared as a
+    sorted multiset rather than edge by edge."""
     n = 8
-    cfg = ADMMConfig(max_iters=60)
+    cfg = ADMMConfig(max_iters=1500)
     g0 = _warm(n, 3)
     spec = E.make_homo_spec(n, 14, cfg)
     states = [E.init_state(spec, jnp.asarray(g0), 0.4) for _ in range(2)]
@@ -124,7 +130,10 @@ def test_sweep_over_budgets():
     for r, out in zip(rs, outs):
         assert int((out.g > 1e-8).sum()) <= r
         single = HomogeneousADMM(n, r, cfg).solve(g0=g0, lam0=0.4)
-        assert out.lam_tilde == pytest.approx(single.lam_tilde, abs=1e-3)
+        assert out.lam_tilde == pytest.approx(single.lam_tilde, abs=1e-9)
+        np.testing.assert_allclose(np.sort(out.g[out.g > 1e-8]),
+                                   np.sort(single.g[single.g > 1e-8]),
+                                   atol=1e-9)
 
 
 def test_dynamic_r_projections_match_static():

@@ -79,14 +79,19 @@ def test_supported_matrix_counts():
 
 
 def test_topology_cache_roundtrip(tmp_path, monkeypatch):
+    """The BA topology is memoized in-process and never touches the disk: a
+    fresh solve from the same seed reproduces it exactly."""
     import repro.launch.steps as steps
-    monkeypatch.setattr(steps, "TOPO_CACHE", str(tmp_path / "cache.json"))
+    monkeypatch.chdir(tmp_path)
     steps._MEM_CACHE.clear()
     t1 = steps.topology_for(8, kind="ba", r=12)
+    assert steps.topology_for(8, kind="ba", r=12) is t1     # memo hit
     steps._MEM_CACHE.clear()
-    t2 = steps.topology_for(8, kind="ba", r=12)  # from disk cache
+    t2 = steps.topology_for(8, kind="ba", r=12)             # solved again
+    assert t2 is not t1
     assert t1.edges == t2.edges
-    np.testing.assert_allclose(t1.g, t2.g)
+    np.testing.assert_array_equal(t1.g, t2.g)
+    assert not list(tmp_path.iterdir())
 
 
 def test_trivial_topologies():
@@ -121,3 +126,26 @@ def test_accum_grad_equivalence():
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32), atol=2e-4)
+
+
+def test_compile_cache_dir(monkeypatch):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins and the code sets nothing;
+    otherwise the cache goes to the fixed ``<repo>/.jax_cache``."""
+    import os
+
+    from repro.launch import compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.REPO_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert (compile_cache.enable_compile_cache()
+                == compile_cache.REPO_CACHE_DIR)
+        assert (jax.config.jax_compilation_cache_dir
+                == compile_cache.REPO_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -6,8 +6,6 @@ import sys
 
 import pytest
 
-import jax
-
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -16,8 +14,9 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models.moe import init_moe, moe_forward
 from repro.models.moe_ep import moe_forward_expert_parallel
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 for E, k, cf in [(8, 2, 8.0), (4, 1, 8.0), (16, 4, 8.0)]:
     d, F = 32, 64
@@ -52,10 +51,6 @@ print("EP_GRAD_OK")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="requires the jax>=0.6 top-level set_mesh API "
-           "(capability check — the subprocess script enters the mesh with it)")
 def test_expert_parallel_moe_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"

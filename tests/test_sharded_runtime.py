@@ -7,8 +7,6 @@ import sys
 
 import pytest
 
-import jax
-
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -23,8 +21,9 @@ from repro.core.graph import weight_matrix_from_weights
 from repro.dsgd import schedule_from_topology
 from repro.dsgd.gossip import gossip_shard, gossip_sim
 from repro.roofline import collective_bytes_from_hlo
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 n = 4
 
 # --- 1. ppermute gossip == dense W matmul on a real multi-device mesh ------
@@ -102,10 +101,6 @@ print("SHARDED_STEP_OK")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not (hasattr(jax, "set_mesh") and hasattr(jax, "shard_map")),
-    reason="requires the jax>=0.6 top-level set_mesh/shard_map APIs "
-           "(capability check — the subprocess script uses both)")
 def test_multi_device_runtime():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
