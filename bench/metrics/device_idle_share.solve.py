@@ -1,0 +1,9 @@
+"""Share of the traced solve window in which no operation ran on the device,
+in %: 1 minus the union of device-operation intervals over the window from
+the first ``bench.solve`` span's start to the last one's end."""
+
+
+def read(ctx):
+    if not ctx.trace:
+        return None
+    return 100.0 * ctx.trace["idle_share"]
