@@ -15,7 +15,8 @@ TPU, and it must be run from a checkout (it imports ``src/repro``). Phases:
    best classic baseline (ring / grid / torus / hypercube with Metropolis
    weights) that fits the same budget and constraints — none fits the node
    scenario's per-node degree allocation, which the output then says.
-   Cold and warm wall times and the phase profile are printed.
+   Cold and warm wall times, the phase profile and the warm solve's
+   ADMM/CG iteration counters are printed.
 3. gossip training of smollm-135m at its published widths (30 layers,
    d_model 576, vocab 49 152, bf16) through ``repro.launch.train.main``
    with ``--topo ba --elastic`` (fault-free), workers stacked on the chip,
@@ -199,7 +200,8 @@ def solve_checked(label: str, req_kw: dict, cfg) -> None:
         r_asym_abs_diff=abs(host - res.r_asym),
         best_classic=base_name, best_classic_r_asym=base_val,
         cold_s=times[0], warm_s=times[1],
-        cold_profile_s=cold_profile, warm_profile_s=res.profile.phases)
+        cold_profile_s=cold_profile, warm_profile_s=res.profile.phases,
+        warm_counts=res.profile.counts)
 
 
 def topology_phase() -> None:

@@ -20,9 +20,9 @@ This module refactors it into a *pipelined anytime* design:
     timings: every stage keeps an EMA cost estimate (seedable from tracked
     bench rows via ``seed_profile``) and is skipped once an incumbent
     exists and the estimate no longer fits the remaining budget.
-  - :class:`PhaseProfile` — the documented profile schema (phase → seconds,
-    ``merge()``/``ms()`` helpers, legacy ``*_s`` dict round-trip), ending
-    the ad-hoc mix of ``queue_s``/``solve_s`` seconds vs per-phase keys.
+  - :class:`~repro.obs.PhaseProfile` (re-exported here) — phase → seconds
+    and ADMM/CG counters per solve; every phase is also a
+    ``repro.solve.<phase>`` span on the profiler's clock (``repro.obs``).
 
 Parity contract: with ``budget_ms=None`` the candidate set, the candidate
 *order* used for tie-breaking, and every numeric kernel call (single-item
@@ -37,6 +37,7 @@ exception, mirroring the service invariant.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import time
 from dataclasses import dataclass, field, replace
@@ -44,6 +45,8 @@ from typing import Iterator
 
 import numpy as np
 
+from .. import obs
+from ..obs import PhaseProfile
 from .constraints import ConstraintSet
 from .graph import Topology, all_edges, is_connected
 from .weights import metropolis_weights, polish_weights, polish_weights_batched
@@ -170,61 +173,6 @@ def resolve_scenario(n: int, r: int, scenario: str,
     return cs, _homo_degree_targets(n, r), meta
 
 
-@dataclass
-class PhaseProfile:
-    """Documented per-phase wall-time profile: phase name → SECONDS.
-
-    Canonical phases: ``prep`` (validation + scenario resolution),
-    ``warm`` (greedy init + SA), ``admm``, ``round`` (support extraction +
-    repair), ``polish``, ``eval`` (invariants + spectral), ``classic``
-    (fallback construction), ``queue``/``solve`` (service-side). Seconds
-    everywhere; use :meth:`ms` for milliseconds — this replaces the old
-    ad-hoc mix of ``*_s`` dict keys and per-phase ms values.
-    """
-
-    phases: dict[str, float] = field(default_factory=dict)
-
-    def add(self, phase: str, seconds: float) -> None:
-        self.phases[phase] = self.phases.get(phase, 0.0) + float(seconds)
-
-    def merge(self, other: "PhaseProfile | dict") -> "PhaseProfile":
-        """New profile with the phase times of both operands summed."""
-        out = PhaseProfile(dict(self.phases))
-        src = other.phases if isinstance(other, PhaseProfile) else \
-            PhaseProfile.from_dict(other).phases
-        for k, v in src.items():
-            out.add(k, v)
-        return out
-
-    def ms(self, phase: str) -> float:
-        return 1e3 * self.phases.get(phase, 0.0)
-
-    @property
-    def total_s(self) -> float:
-        return float(sum(self.phases.values()))
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "PhaseProfile":
-        """Parse a legacy profile dict: ``<phase>_s`` values are seconds,
-        ``<phase>_ms`` milliseconds, bare numeric keys seconds."""
-        out = cls()
-        for k, v in d.items():
-            if not isinstance(v, (int, float)) or isinstance(v, bool):
-                continue
-            if k.endswith("_ms"):
-                out.add(k[:-3], v / 1e3)
-            elif k.endswith("_s"):
-                out.add(k[:-2], v)
-            else:
-                out.add(k, v)
-        return out
-
-    def to_dict(self) -> dict:
-        """Legacy ``<phase>_s`` dict view (seconds), for consumers of the
-        pre-§17 profile plumbing."""
-        return {f"{k}_s": v for k, v in self.phases.items()}
-
-
 @dataclass(frozen=True)
 class Incumbent:
     """One best-so-far point of an anytime solve."""
@@ -309,7 +257,7 @@ class AnytimeSolver:
         _api._validate_pipeline_cfg(cfg)
         self.request = request
         self.cfg = cfg
-        self.profile = PhaseProfile()
+        self.profile = PhaseProfile(area="solve", clock=clock)
         self.incumbent: Incumbent | None = None
         self.complete = False
         self.reasons: list[str] = []
@@ -366,11 +314,15 @@ class AnytimeSolver:
             return True
         return est * _SAFETY <= max(rem, 0.0)
 
-    def _observe(self, stage: str, phase: str, dt: float) -> None:
-        self.profile.add(phase, dt)
+    @contextlib.contextmanager
+    def _stage(self, stage: str, **ids):
+        """Time the block as profile phase ``stage`` and, when it ends
+        normally, fold its seconds into the stage's EMA cost estimate."""
+        with self.profile.phase(stage, **ids) as ph:
+            yield
         prev = self._est.get(stage)
-        self._est[stage] = (dt if prev is None
-                            else (1 - _EST_ALPHA) * prev + _EST_ALPHA * dt)
+        self._est[stage] = (ph.seconds if prev is None else
+                            (1 - _EST_ALPHA) * prev + _EST_ALPHA * ph.seconds)
 
     # -- public handle ---------------------------------------------------
 
@@ -406,27 +358,26 @@ class AnytimeSolver:
     # -- candidate machinery --------------------------------------------
 
     def _offer(self, sel: np.ndarray, topo: Topology, order: int, tier: str,
-               source: str, polished: bool) -> Incumbent | None:
+               source: str, polished: bool, **ids) -> Incumbent | None:
         """Evaluate a candidate (one invariant check + one r_asym per
         distinct (support, weighting), like ``api._pick_best``) and install
         it as incumbent when it wins the lexicographic (r_asym, candidate
         order) comparison — exactly the barrier's first-strict-minimum
-        selection."""
+        selection. ``ids`` label the ``eval`` span."""
         from .guard import check_invariants
 
         key = (np.asarray(sel, dtype=bool).tobytes(), polished)
-        t0 = self._clock()
-        if key not in self._inv_cache:
-            self._inv_cache[key] = check_invariants(topo)
-        bad = self._inv_cache[key]
+        with self._stage("eval", **ids):
+            if key not in self._inv_cache:
+                self._inv_cache[key] = check_invariants(topo)
+            bad = self._inv_cache[key]
+            if bad is None:
+                if key not in self._val_cache:
+                    self._val_cache[key] = topo.r_asym()
+                val = self._val_cache[key]
         if bad is not None:
-            self._observe("eval", "eval", self._clock() - t0)
             self._failures.append(f"{topo.name}: {bad}")
             return None
-        if key not in self._val_cache:
-            self._val_cache[key] = topo.r_asym()
-        val = self._val_cache[key]
-        self._observe("eval", "eval", self._clock() - t0)
         if val < self._best_val or (val == self._best_val
                                     and order < self._best_order):
             topo.meta["selected_from"] = source
@@ -441,12 +392,13 @@ class AnytimeSolver:
         return None
 
     def _polish_and_offer(self, sel: np.ndarray, name: str, meta: dict,
-                          order: int, tier: str, source: str,
+                          order: int, tier: str, source: str, **ids,
                           ) -> Incumbent | None:
         """Connectivity-check + polish + evaluate one candidate selection —
         the single-item mirror of ``api._finalize_batch`` (bit-equal: the
         device polish is batch-size invariant), with polished weights
-        cached per distinct support like the barrier's dedup."""
+        cached per distinct support like the barrier's dedup. ``ids``
+        label the ``polish`` and ``eval`` spans."""
         n = int(self.request.n)
         cfg = self.cfg
         edges_full = all_edges(n)
@@ -456,22 +408,22 @@ class AnytimeSolver:
         skey = np.asarray(sel, dtype=bool).tobytes()
         g = self._g_cache.get(skey)
         if g is None:
-            t0 = self._clock()
-            g0 = metropolis_weights(n, edges)
-            if cfg.polish == "device":
-                g = polish_weights_batched(n, [edges], [g0],
-                                           iters=cfg.polish_iters,
-                                           dtype=cfg.polish_dtype)[0]
-            else:
-                g = polish_weights(n, edges, g0, iters=cfg.polish_iters)
-            self._observe("polish", "polish", self._clock() - t0)
+            with self._stage("polish", **ids):
+                g0 = metropolis_weights(n, edges)
+                if cfg.polish == "device":
+                    g = polish_weights_batched(n, [edges], [g0],
+                                               iters=cfg.polish_iters,
+                                               dtype=cfg.polish_dtype)[0]
+                else:
+                    g = polish_weights(n, edges, g0, iters=cfg.polish_iters)
             self._g_cache[skey] = g
         topo = Topology(n, edges, g, name=name,
                         meta={**meta, "connected": True})
-        return self._offer(sel, topo, order, tier, source, polished=True)
+        return self._offer(sel, topo, order, tier, source, polished=True,
+                           **ids)
 
     def _preview(self, edges: list, order: int, tier: str, source: str,
-                 name: str) -> Incumbent | None:
+                 name: str, **ids) -> Incumbent | None:
         """Budget-mode-only cheap candidate: Metropolis weights, no polish."""
         n = int(self.request.n)
         if not edges or not is_connected(n, edges):
@@ -484,7 +436,7 @@ class AnytimeSolver:
         g = metropolis_weights(n, edges)
         topo = Topology(n, edges, g, name=name, meta={"connected": True})
         return self._offer(eidx_sel, topo, order, tier, source,
-                           polished=False)
+                           polished=False, **ids)
 
     # -- the stage graph -------------------------------------------------
 
@@ -511,10 +463,9 @@ class AnytimeSolver:
                     "warm starts, classics) was disconnected under the "
                     "constraints; raise r or relax the ConstraintSet")
             # budgeted and empty-handed: the guaranteed closed-form answer
-            t0 = self._clock()
-            fb = classic_fallback(n, r,
-                                  self._cs if scenario != "homo" else None)
-            self.profile.add("classic", self._clock() - t0)
+            with self.profile.phase("classic"):
+                fb = classic_fallback(
+                    n, r, self._cs if scenario != "homo" else None)
             self.reasons.append("budget expired — classic fallback")
             sel = np.zeros(len(all_edges(n)), dtype=bool)
             from .graph import edge_index
@@ -532,11 +483,10 @@ class AnytimeSolver:
 
         req, cfg = self.request, self.cfg
         n, r, scenario = int(req.n), int(req.r), req.scenario
-        t0 = self._clock()
-        cs, deg_targets, meta = resolve_scenario(
-            n, r, scenario, req.cs, req.node_bandwidths, context="api")
+        with self.profile.phase("prep"):
+            cs, deg_targets, meta = resolve_scenario(
+                n, r, scenario, req.cs, req.node_bandwidths, context="api")
         self._cs = cs
-        self.profile.add("prep", self._clock() - t0)
         R = max(1, cfg.restarts)
         use_z = scenario != "homo"
         sa_cs = cs if scenario != "homo" else None
@@ -564,12 +514,11 @@ class AnytimeSolver:
             if not self._fits("warm"):
                 self._skip(f"restart {k}", "warm")
                 continue
-            t0 = self._clock()
-            edges0, seed = _api._init_graph(n, r, scenario, cs, deg_targets,
-                                            cfg, k)
-            annealed = yield from self._anneal(
-                n, edges0, seed, sa_cs, cfg, k, previews)
-            self._observe("warm", "warm", self._clock() - t0)
+            with self._stage("warm", restart=k):
+                edges0, seed = _api._init_graph(n, r, scenario, cs,
+                                                deg_targets, cfg, k)
+                annealed = yield from self._anneal(
+                    n, edges0, seed, sa_cs, cfg, k, previews)
             if self._expired():
                 self._note_expiry(f"restart {k} (post-SA)")
                 return
@@ -580,7 +529,7 @@ class AnytimeSolver:
                 inc = self._polish_and_offer(
                     warm[1].astype(bool), f"ba-topo(n={n},r={r},warm)",
                     dict(meta), order=2 * k + 1, tier="warm",
-                    source="warm-start")
+                    source="warm-start", restart=k)
                 if inc is not None:
                     yield inc
             else:
@@ -591,22 +540,22 @@ class AnytimeSolver:
             if self._expired():
                 self._note_expiry(f"restart {k} (pre-ADMM)")
                 return
-            t0 = self._clock()
             g0, z0, lam0 = warm
-            if scenario == "homo":
-                res = solver.solve(g0=g0, lam0=lam0)
-            else:
-                res = solver.solve(g0=g0, z0=z0, lam0=lam0)
-            self._observe("admm", "admm", self._clock() - t0)
-            t0 = self._clock()
-            items, _ = _api._candidate_items(n, r, [warm], [res], cs, cfg,
-                                             meta, use_z=use_z)
-            self.profile.add("round", self._clock() - t0)
+            with self._stage("admm", restart=k):
+                if scenario == "homo":
+                    res = solver.solve(g0=g0, lam0=lam0)
+                else:
+                    res = solver.solve(g0=g0, z0=z0, lam0=lam0)
+            self.profile.count("admm_iters", res.iters)
+            self.profile.count("cg_iters", res.cg_iters)
+            with self.profile.phase("round", restart=k):
+                items, _ = _api._candidate_items(n, r, [warm], [res], cs,
+                                                 cfg, meta, use_z=use_z)
             admm_sel, admm_name, admm_meta = items[0]
             if self._fits("polish") or self.incumbent is None:
                 inc = self._polish_and_offer(
                     admm_sel, admm_name, admm_meta, order=2 * k,
-                    tier="warm", source="admm")
+                    tier="warm", source="admm", restart=k)
                 if inc is not None:
                     yield inc
             else:
@@ -640,7 +589,8 @@ class AnytimeSolver:
                 inc = self._preview(
                     best_edges, _PREVIEW_ORDER, "sa_only",
                     f"sa-preview:restart{k}",
-                    f"ba-topo(n={n},r={int(self.request.r)},sa@{t})")
+                    f"ba-topo(n={n},r={int(self.request.r)},sa@{t})",
+                    restart=k)
                 if inc is not None:
                     yield inc
             if self._expired() or not self._fits("warm_chunk"):
@@ -682,28 +632,33 @@ def solve_topology(request: TopologyRequest, *, cfg=None,
     ``engine="barrier"`` runs the preserved phase-barriered pipeline
     (exactly the pre-§17 ``optimize_topology``) — benchmarks use it as the
     comparison arm. ``profile``, when a dict, receives the legacy
-    ``<phase>_s`` keys in both engines.
+    ``<phase>_s`` keys in both engines. The whole request runs under one
+    ``repro.solve`` span carrying ``n``, ``r`` and the solver seed.
     """
-    if engine == "barrier":
-        from . import api as _api
+    from . import api as _api
 
-        prof: dict = {} if profile is None else profile
-        t0 = time.perf_counter()
-        topo = _api._optimize_request(
-            int(request.n), int(request.r), scenario=request.scenario,
-            cs=request.cs, node_bandwidths=request.node_bandwidths,
-            cfg=cfg, profile=prof)
-        return TopologyResult(
-            topology=topo, r_asym=float(topo.meta["r_asym"]),
-            quality_tier="full",
-            elapsed_ms=(time.perf_counter() - t0) * 1e3,
-            profile=PhaseProfile.from_dict(prof), complete=True,
-            request=request)
-    if engine != "anytime":
+    if engine not in ("anytime", "barrier"):
         raise ValueError(f"unknown engine {engine!r}; "
                          "expected 'anytime' or 'barrier'")
-    solver = AnytimeSolver(request, cfg, seed_profile=seed_profile)
-    res = solver.solve(budget_ms=budget_ms)
+    seed = (request.seed if request.seed is not None and engine == "anytime"
+            else (cfg or _api.BATopoConfig()).seed)
+    with obs.span("solve", n=int(request.n), r=int(request.r),
+                  seed=int(seed)):
+        if engine == "barrier":
+            prof: dict = {} if profile is None else profile
+            t0 = time.perf_counter()
+            topo = _api._optimize_request(
+                int(request.n), int(request.r), scenario=request.scenario,
+                cs=request.cs, node_bandwidths=request.node_bandwidths,
+                cfg=cfg, profile=prof)
+            return TopologyResult(
+                topology=topo, r_asym=float(topo.meta["r_asym"]),
+                quality_tier="full",
+                elapsed_ms=(time.perf_counter() - t0) * 1e3,
+                profile=PhaseProfile.from_dict(prof), complete=True,
+                request=request)
+        solver = AnytimeSolver(request, cfg, seed_profile=seed_profile)
+        res = solver.solve(budget_ms=budget_ms)
     if profile is not None:
         profile.update(res.profile.to_dict())
     return res

@@ -30,7 +30,6 @@ fixed n the whole cardinality sweep runs as one vmapped solve.
 """
 from __future__ import annotations
 
-import time
 import warnings
 from dataclasses import dataclass, field
 
@@ -444,71 +443,80 @@ def _optimize_request(
     repair + polish) wins. Pass ``profile={}`` to collect the per-phase
     wall-time breakdown (keys ``warm_s/admm_s/round_s/polish_s/eval_s``).
     """
+    from ..obs import PhaseProfile
     from .anytime import resolve_scenario
 
     cfg = cfg or BATopoConfig()
     _validate_pipeline_cfg(cfg)
-    prof = {} if profile is None else profile
+    prof = PhaseProfile(area="solve")
     cs, deg_targets, meta = resolve_scenario(n, r, scenario, cs,
                                              node_bandwidths, context="api")
+    try:
+        return _barrier_phases(n, r, scenario, cs, deg_targets, meta, cfg,
+                               prof)
+    finally:
+        if profile is not None:
+            prof.add_to(profile)
 
+
+def _barrier_phases(n, r, scenario, cs, deg_targets, meta, cfg, prof):
+    """The five barriered phases of ``_optimize_request``, each timed into
+    ``prof`` under its ``repro.solve.<phase>`` span."""
     # ---- phase 1: warm starts (device SA by default) ----------------------
-    t0 = time.perf_counter()
     n_restarts = max(1, cfg.restarts)
-    warms = _warm_starts(n, r, scenario, cs, deg_targets, cfg, n_restarts)
-    prof["warm_s"] = prof.get("warm_s", 0.0) + time.perf_counter() - t0
+    with prof.phase("warm"):
+        warms = _warm_starts(n, r, scenario, cs, deg_targets, cfg, n_restarts)
 
     solver = _make_solver(n, r, scenario, cs, cfg)
 
     # ---- phase 2: ADMM — batched restarts in one device call (scan driver
     # only; an explicit driver="python" request keeps the per-restart loop)
-    t0 = time.perf_counter()
-    if (n_restarts > 1 and cfg.admm.solver != "kkt_bicgstab_ilu"
-            and cfg.admm.driver == "scan"):
-        g0s = np.stack([w[0] for w in warms])
-        lam0s = np.asarray([w[2] for w in warms])
-        if scenario == "homo":
-            results = solver.solve_batched(g0s, lam0s)
+    with prof.phase("admm"):
+        if (n_restarts > 1 and cfg.admm.solver != "kkt_bicgstab_ilu"
+                and cfg.admm.driver == "scan"):
+            g0s = np.stack([w[0] for w in warms])
+            lam0s = np.asarray([w[2] for w in warms])
+            if scenario == "homo":
+                results = solver.solve_batched(g0s, lam0s)
+            else:
+                results = solver.solve_batched(
+                    g0s, np.stack([w[1] for w in warms]), lam0s)
+        elif scenario == "homo":
+            results = [solver.solve(g0=g0, lam0=lam0)
+                       for g0, _, lam0 in warms]
         else:
-            results = solver.solve_batched(g0s, np.stack([w[1] for w in warms]), lam0s)
-    elif scenario == "homo":
-        results = [solver.solve(g0=g0, lam0=lam0) for g0, _, lam0 in warms]
-    else:
-        results = [solver.solve(g0=g0, z0=z0, lam0=lam0) for g0, z0, lam0 in warms]
-    prof["admm_s"] = prof.get("admm_s", 0.0) + time.perf_counter() - t0
+            results = [solver.solve(g0=g0, z0=z0, lam0=lam0)
+                       for g0, z0, lam0 in warms]
 
     # ---- phase 3: rounding + greedy feasibility repair --------------------
-    t0 = time.perf_counter()
-    items, sources = _candidate_items(n, r, warms, results, cs, cfg, meta,
-                                      use_z=(scenario != "homo"))
-    prof["round_s"] = prof.get("round_s", 0.0) + time.perf_counter() - t0
+    with prof.phase("round"):
+        items, sources = _candidate_items(n, r, warms, results, cs, cfg, meta,
+                                          use_z=(scenario != "homo"))
 
     # ---- phase 4: weight polish, all candidates in one batched call -------
-    t0 = time.perf_counter()
-    topos = _finalize_batch(n, items, cfg, cs)
-    prof["polish_s"] = prof.get("polish_s", 0.0) + time.perf_counter() - t0
+    with prof.phase("polish"):
+        topos = _finalize_batch(n, items, cfg, cs)
 
     # ---- phase 5: release validation + spectral evaluation (one invariant
     # check and one r_asym per distinct support) ----------------------------
-    t0 = time.perf_counter()
-    best_topo, best_val, failures = _pick_best(n, items, topos, sources)
-    if best_topo is None:
-        if failures:
-            from .guard import TopologyInvariantError
+    with prof.phase("eval"):
+        best_topo, best_val, failures = _pick_best(n, items, topos, sources)
+        if best_topo is None:
+            if failures:
+                from .guard import TopologyInvariantError
 
-            bad = failures[0].rsplit(": ", 1)[-1]
-            raise TopologyInvariantError(
-                f"no candidate topology for n={n}, r={r}, "
-                f"scenario={scenario!r} passed release validation — first "
-                f"failure: {failures[0]!r} (all: {failures})",
-                invariant=bad, failures=failures)
-        raise ValueError(
-            f"failed to construct any connected topology for n={n}, r={r}, "
-            f"scenario={scenario!r} — every candidate (ADMM, warm starts, "
-            "classics) was disconnected under the constraints; raise r or "
-            "relax the ConstraintSet")
-    best_topo.meta["r_asym"] = best_val
-    prof["eval_s"] = prof.get("eval_s", 0.0) + time.perf_counter() - t0
+                bad = failures[0].rsplit(": ", 1)[-1]
+                raise TopologyInvariantError(
+                    f"no candidate topology for n={n}, r={r}, "
+                    f"scenario={scenario!r} passed release validation — "
+                    f"first failure: {failures[0]!r} (all: {failures})",
+                    invariant=bad, failures=failures)
+            raise ValueError(
+                f"failed to construct any connected topology for n={n}, "
+                f"r={r}, scenario={scenario!r} — every candidate (ADMM, warm "
+                "starts, classics) was disconnected under the constraints; "
+                "raise r or relax the ConstraintSet")
+        best_topo.meta["r_asym"] = best_val
     return best_topo
 
 

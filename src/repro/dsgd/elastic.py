@@ -64,6 +64,8 @@ from repro.core.reopt import (
     ReoptResult,
     reoptimize_topology,
 )
+from repro import obs
+from repro.obs import PhaseProfile
 from repro.optim import apply_updates
 
 from .chaos import ChaosSpec, degrade_matrix
@@ -207,6 +209,10 @@ class RoundReport:
     reopt: ReoptResult | None = None  # set when the detector fired this round
     reopt_reason: str | None = None
     swapped: bool = False             # a pending topology activated this round
+    # host seconds of the round's phases: plan (step read, swap, watchdog,
+    # mask uploads), dispatch (the step call), sync (the loss read), reopt
+    profile: PhaseProfile = field(
+        default_factory=lambda: PhaseProfile(area="round"))
 
 
 class ElasticHooks:
@@ -267,17 +273,19 @@ def make_elastic_train_step(cfg, opt_update: Callable, *,
     def _step(state: DSGDState, batch, W, alive, link_up, mix_mask,
               nbr_idx=None, nbr_mask=None):
         losses, grads = jax.vmap(jax.value_and_grad(loss_fn))(state.params, batch)
-        updates, opt = jax.vmap(opt_update)(grads, state.opt, state.params)
-        local = jax.vmap(apply_updates)(state.params, updates)
-        W_eff = degrade_matrix(W, mix_mask, link_up)
-        if use_kernel:
-            from repro.kernels.gossip_mix.ops import gossip_mix_batched
+        with obs.scope("optimizer"):
+            updates, opt = jax.vmap(opt_update)(grads, state.opt, state.params)
+            local = jax.vmap(apply_updates)(state.params, updates)
+        with obs.scope("gossip"):
+            W_eff = degrade_matrix(W, mix_mask, link_up)
+            if use_kernel:
+                from repro.kernels.gossip_mix.ops import gossip_mix_batched
 
-            weights = gather_neighbor_weights(W_eff, nbr_idx, nbr_mask)
-            mixed = jax.tree.map(
-                lambda x: gossip_mix_batched(x, nbr_idx, weights), local)
-        else:
-            mixed = gossip_sim_tree(local, W_eff)
+                weights = gather_neighbor_weights(W_eff, nbr_idx, nbr_mask)
+                mixed = jax.tree.map(
+                    lambda x: gossip_mix_batched(x, nbr_idx, weights), local)
+            else:
+                mixed = gossip_sim_tree(local, W_eff)
         params = jax.tree.map(
             lambda mx, lc, od: jnp.where(
                 _bmask(mix_mask, mx), mx, jnp.where(_bmask(alive, lc), lc, od)),
@@ -321,9 +329,12 @@ def make_elastic_sharded_train_step(cfg, sched: GossipSchedule,
         p1, o1 = sq(params), sq(opt)
         b1 = sq(batch)
         loss, grads = jax.value_and_grad(loss_fn)(p1, b1)
-        updates, o2 = opt_update(grads, o1, p1)
-        p2 = apply_updates(p1, updates)
-        pm = gossip_shard_elastic(p2, sched, axis, mix_mask, w_self, w_recv)
+        with obs.scope("optimizer"):
+            updates, o2 = opt_update(grads, o1, p1)
+            p2 = apply_updates(p1, updates)
+        with obs.scope("gossip"):
+            pm = gossip_shard_elastic(p2, sched, axis, mix_mask, w_self,
+                                      w_recv)
         i = jax.lax.axis_index(axis)
         a_i, m_i = alive[i] > 0, mix_mask[i] > 0
         p_out = jax.tree.map(
@@ -419,46 +430,51 @@ class ElasticRuntime:
     def round(self, state: DSGDState, es: ElasticState, batch
               ) -> tuple[DSGDState, dict, RoundReport]:
         spec, ch = self.spec, self.spec.chaos
-        t = int(state.step)
-        ti = min(t, ch.steps - 1)
-        alive_np = np.asarray(ch.alive[ti]) > 0
-        bw_np = np.asarray(ch.bandwidth[ti], np.float64)
+        prof = PhaseProfile(area="round")
+        with prof.phase("plan"):
+            t = int(state.step)
+            ti = min(t, ch.steps - 1)
+            alive_np = np.asarray(ch.alive[ti]) > 0
+            bw_np = np.asarray(ch.bandwidth[ti], np.float64)
 
-        swapped = False
-        if es.pending is not None and t >= es.pending[0]:
-            self._adopt(es, es.pending[1], t, bw_np, ch.alive[ti])
-            swapped = True
+            swapped = False
+            if es.pending is not None and t >= es.pending[0]:
+                self._adopt(es, es.pending[1], t, bw_np, ch.alive[ti])
+                swapped = True
 
-        # watchdog: modeled latencies vs the round deadline
-        lat = node_step_latency_ms(es.topology, ch, ti, spec.const)
-        dropped = np.zeros(self.n, bool)
-        if spec.drop_stragglers:
-            dropped = alive_np & (lat > self.deadline_ms)
-            if dropped.all() or not (alive_np & ~dropped).any():
-                dropped[:] = False          # the watchdog cannot drop everyone
-        mix_np = (alive_np & ~dropped).astype(np.float32)
-        participants = lat[alive_np & ~dropped]
-        round_ms = float(participants.max()) if participants.size else 0.0
-        if dropped.any():
-            # the watchdog waits until the deadline to declare the drop
-            round_ms = max(round_ms, self.deadline_ms)
-            es.dropped_rounds += 1
-            es.drops += int(dropped.sum())
+            # watchdog: modeled latencies vs the round deadline
+            lat = node_step_latency_ms(es.topology, ch, ti, spec.const)
+            dropped = np.zeros(self.n, bool)
+            if spec.drop_stragglers:
+                dropped = alive_np & (lat > self.deadline_ms)
+                if dropped.all() or not (alive_np & ~dropped).any():
+                    dropped[:] = False      # the watchdog cannot drop everyone
+            mix_np = (alive_np & ~dropped).astype(np.float32)
+            participants = lat[alive_np & ~dropped]
+            round_ms = float(participants.max()) if participants.size else 0.0
+            if dropped.any():
+                # the watchdog waits until the deadline to declare the drop
+                round_ms = max(round_ms, self.deadline_ms)
+                es.dropped_rounds += 1
+                es.drops += int(dropped.sum())
+
+            alive_d = jnp.asarray(ch.alive[ti], jnp.float32)
+            link_d = jnp.asarray(ch.link_up[ti], jnp.float32)
+            mix_d = jnp.asarray(mix_np)
 
         # bounded retry/backoff ladder (run_ladder semantics: classified
         # rung reports, never raises; terminal rung freezes the round)
-        alive_d = jnp.asarray(ch.alive[ti], jnp.float32)
-        link_d = jnp.asarray(ch.link_up[ti], jnp.float32)
-        mix_d = jnp.asarray(mix_np)
         rungs: list[RungReport] = []
         new_state = metrics = None
         attempts = 0
         for k in range(spec.max_round_retries + 1):
             attempts = k + 1
             ab = self.hooks.on_attempt(t, k, batch)
-            cand_state, cand_metrics = self._run(state, ab, es, alive_d,
-                                                 link_d, mix_d)
-            loss = float(cand_metrics["loss"])
+            with prof.phase("dispatch", step=t):
+                cand_state, cand_metrics = self._run(state, ab, es, alive_d,
+                                                     link_d, mix_d)
+            with prof.phase("sync", step=t):
+                loss = float(cand_metrics["loss"])
             name = "round" if k == 0 else f"retry{k}"
             if np.isfinite(loss):
                 rungs.append(RungReport(name, "ok"))
@@ -478,17 +494,20 @@ class ElasticRuntime:
 
         # drift detection → warm re-optimization → deferred adoption
         reopt_res, reason = None, None
-        if spec.reopt and es.pending is None:
-            reason = es.detector.check(t, bw_np, ch.alive[ti])
-            if reason is not None:
-                reopt_res = self._reoptimize(es, t, bw_np, ch.alive[ti], reason)
+        with prof.phase("reopt", step=t):
+            if spec.reopt and es.pending is None:
+                reason = es.detector.check(t, bw_np, ch.alive[ti])
+                if reason is not None:
+                    reopt_res = self._reoptimize(es, t, bw_np, ch.alive[ti],
+                                                 reason)
 
         es.data_step += 1
         es.key = jax.random.fold_in(es.key, t)
         report = RoundReport(step=t, alive=alive_np, dropped=dropped,
                              round_ms=round_ms, deadline_ms=self.deadline_ms,
                              attempts=attempts, rungs=rungs, reopt=reopt_res,
-                             reopt_reason=reason, swapped=swapped)
+                             reopt_reason=reason, swapped=swapped,
+                             profile=prof)
         return new_state, metrics, report
 
     def _run(self, state, batch, es: ElasticState, alive, link_up, mix):

@@ -102,7 +102,9 @@ def _dynamic_step(cfg, topo, opt_update):
 def main(argv: list[str] | None = None) -> dict:
     """Run the training loop; returns the run record (also written to
     ``--json-out``): config, topology, per-logged-step metrics with the
-    step's wall time ``step_s``, and the elastic trail."""
+    step's wall time ``step_s`` (elastic: and the round's host split
+    ``plan_s``/``dispatch_s``/``sync_s``/``reopt_s``), and the elastic
+    trail."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -237,8 +239,10 @@ def main(argv: list[str] | None = None) -> dict:
         data_step = es.data_step if args.elastic else s
         per = [synthetic_lm_batch(dc, data_step, node=i) for i in range(n)]
         batch = {k: jnp.stack([b[k] for b in per]) for k in per[0]}
+        phases = {}
         if args.elastic:
             state, metrics, rep = runtime.round(state, es, batch)
+            phases = rep.profile.to_dict()
             modeled_ms += rep.round_ms
             if rep.dropped.any() or rep.swapped or rep.reopt is not None:
                 elastic_log.append(
@@ -252,7 +256,7 @@ def main(argv: list[str] | None = None) -> dict:
             m = {k: float(v) for k, v in metrics.items()}
             m.update(step=s, step_s=time.perf_counter() - t_step,
                      wall_s=round(time.time() - t0, 1),
-                     modelled_time_s=round(modeled_ms / 1e3, 4))
+                     modelled_time_s=round(modeled_ms / 1e3, 4), **phases)
             history.append(m)
             print("  " + json.dumps(m))
         if s and s % args.ckpt_every == 0:

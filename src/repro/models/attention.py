@@ -15,6 +15,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from .common import apply_rope, dense_init, softcap
 
 __all__ = ["AttnParams", "init_attn", "attend_full", "attend_chunked", "attn_forward",
@@ -151,11 +152,12 @@ def attn_forward(params, x, cfg, *, window=0, positions=None, cache: KVCache | N
     q, k, v = _qkv(params, x, cfg)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    if chunked and S > 128:
-        out = attend_chunked(q, k, v, window, cfg.attn_logit_softcap)
-    else:
-        mask = _causal_mask(S, window)
-        out = attend_full(q, k, v, mask, cfg.attn_logit_softcap)
+    with obs.scope("attention"):
+        if chunked and S > 128:
+            out = attend_chunked(q, k, v, window, cfg.attn_logit_softcap)
+        else:
+            mask = _causal_mask(S, window)
+            out = attend_full(q, k, v, mask, cfg.attn_logit_softcap)
     new_cache = None
     if cache is not None:
         C = cache.k.shape[1]

@@ -27,6 +27,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from .attention import attn_decode, attn_forward, init_attn, init_kv_cache
 from .common import dense_init, embed_init, rms_norm, softcap
 from .mlp import gelu_mlp, init_gelu_mlp, init_swiglu, swiglu
@@ -170,12 +171,13 @@ def _attn_block(lp, x, cfg, window, positions, *, causal=True, cache=None):
                                 window=window, positions=positions, cache=cache)
     x = x + h
     h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if "moe" in lp:
-        out, aux = _moe(lp["moe"], h2, cfg)
-    elif cfg.arch_type == "audio":
-        out, aux = gelu_mlp(lp["mlp"], h2), 0.0
-    else:
-        out, aux = swiglu(lp["mlp"], h2), 0.0
+    with obs.scope("mlp"):
+        if "moe" in lp:
+            out, aux = _moe(lp["moe"], h2, cfg)
+        elif cfg.arch_type == "audio":
+            out, aux = gelu_mlp(lp["mlp"], h2), 0.0
+        else:
+            out, aux = swiglu(lp["mlp"], h2), 0.0
     return x + out, aux, new_cache
 
 
@@ -373,17 +375,21 @@ def _nll_sum(params, x, labels, cfg):
     over the vocab axis (max / sum / masked-sum) — a ``take_along_axis``
     gather on a vocab-sharded logits tensor forces GSPMD to all-gather the
     full (B, c, V) block per chunk (≈8 GB f32 at V=256k), whereas reductions
-    stay sharded and only their scalar partials cross chips.
+    stay sharded and only their scalar partials cross chips. Runs under the
+    ``repro.logits`` scope.
     """
-    logits = _logits(params, x, cfg)              # (B,c,V) f32
-    valid = labels >= 0
-    safe = jnp.where(valid, labels, 0)
-    m = jnp.max(logits, axis=-1)
-    lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[..., None]), axis=-1))
-    onehot = (jnp.arange(logits.shape[-1])[None, None, :] == safe[..., None])
-    target = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
-    nll = lse - target
-    return jnp.sum(nll * valid).astype(jnp.float32), jnp.sum(valid).astype(jnp.int32)
+    with obs.scope("logits"):
+        logits = _logits(params, x, cfg)          # (B,c,V) f32
+        valid = labels >= 0
+        safe = jnp.where(valid, labels, 0)
+        m = jnp.max(logits, axis=-1)
+        lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[..., None]), axis=-1))
+        onehot = (jnp.arange(logits.shape[-1])[None, None, :]
+                  == safe[..., None])
+        target = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
+        nll = lse - target
+        return (jnp.sum(nll * valid).astype(jnp.float32),
+                jnp.sum(valid).astype(jnp.int32))
 
 
 def loss_chunk_for(cfg, batch_size: int, budget_bytes: float = 2e9) -> int:

@@ -51,7 +51,9 @@ Architecture (one request's life):
 
 Per-phase latency rides the PR-3 ``profile`` dict: the full tier passes it
 straight into ``optimize_topology`` (``warm_s/admm_s/round_s/polish_s/
-eval_s``) and the service adds ``queue_s``/``solve_s``.
+eval_s``) and the service adds ``queue_s``/``solve_s``; a deadlined
+answer from the anytime solver also carries its ``admm_iters``/``cg_iters``
+counters.
 """
 from __future__ import annotations
 
@@ -415,18 +417,22 @@ class TopologyService:
         scenario = req.scenario
         cs, _, _ = resolve_scenario(n, r, scenario, req.cs,
                                     req.node_bandwidths, context="service")
-        t0 = time.perf_counter()
-        warm = self._nearest_warm(req)
-        if warm is None:
-            deg = _homo_degree_targets(n, r) if scenario == "homo" else None
-            edges0, _ = _init_graph(n, r, scenario, cs, deg, self.cfg, 0)
-            warm = _pack_warm(n, edges0)
-        prof["warm_s"] = prof.get("warm_s", 0.0) + time.perf_counter() - t0
-        t0 = time.perf_counter()
-        ladder = run_ladder(jittered_warm_rungs(
-            n, r, scenario, cs, self.cfg, warm,
-            f"ba-topo(n={n},r={r},svc-warm)", self.policy.guard))
-        prof["admm_s"] = prof.get("admm_s", 0.0) + time.perf_counter() - t0
+        phases = PhaseProfile(area="solve")
+        try:
+            with phases.phase("warm"):
+                warm = self._nearest_warm(req)
+                if warm is None:
+                    deg = (_homo_degree_targets(n, r) if scenario == "homo"
+                           else None)
+                    edges0, _ = _init_graph(n, r, scenario, cs, deg,
+                                            self.cfg, 0)
+                    warm = _pack_warm(n, edges0)
+            with phases.phase("admm"):
+                ladder = run_ladder(jittered_warm_rungs(
+                    n, r, scenario, cs, self.cfg, warm,
+                    f"ba-topo(n={n},r={r},svc-warm)", self.policy.guard))
+        finally:
+            phases.add_to(prof)
         if ladder.topology is None:
             raise RuntimeError(f"warm ladder exhausted ({ladder.reason})")
         ladder.topology.meta["ladder_rung"] = ladder.rung
@@ -439,12 +445,15 @@ class TopologyService:
         if self.hooks.sa is not None:
             return self.hooks.sa(req, prof)
         n, r = int(req.n), int(req.r)
-        t0 = time.perf_counter()
-        deg = _homo_degree_targets(n, r) if req.scenario == "homo" else None
-        cs = req.cs if req.scenario != "homo" else None
-        edges0, seed = _init_graph(n, r, req.scenario, cs, deg, self.cfg, 0)
-        edges = _anneal_edges(n, [edges0], [seed], cs, self.cfg)[0]
-        prof["warm_s"] = prof.get("warm_s", 0.0) + time.perf_counter() - t0
+        phases = PhaseProfile(area="solve")
+        with phases.phase("warm"):
+            deg = (_homo_degree_targets(n, r) if req.scenario == "homo"
+                   else None)
+            cs = req.cs if req.scenario != "homo" else None
+            edges0, seed = _init_graph(n, r, req.scenario, cs, deg,
+                                       self.cfg, 0)
+            edges = _anneal_edges(n, [edges0], [seed], cs, self.cfg)[0]
+        phases.add_to(prof)
         if not edges or not is_connected(n, edges):
             return None
         g = metropolis_weights(n, edges)
@@ -546,7 +555,8 @@ class TopologyService:
                                  budget_ms=max(float(remaining), 0.0),
                                  seed_profile=self._seed_profiles.get(n))
             topo, tier, reason = res.topology, res.quality_tier, res.reason
-            prof = {"queue_s": queue_s, **res.profile.to_dict()}
+            prof = {"queue_s": queue_s, **res.profile.to_dict(),
+                    **res.profile.counts}
         except Exception as exc:  # noqa: BLE001 — terminal guard, never raise
             topo, tier = None, None
             reason = f"anytime: {type(exc).__name__}: {exc}"
