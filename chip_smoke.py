@@ -23,7 +23,8 @@ TPU, and it must be run from a checkout (it imports ``src/repro``). Phases:
    seq 512, 5 steps. Four workers, batch 2 per worker or else 1, whichever
    compiled step first fits 75 % of the chip's memory. The BA budget is
    one edge per worker, so W is sparse and its rows differ (a permuted or
-   mis-weighted row shows). Every loss must be finite, and the first
+   mis-weighted row shows). The attention path and its tile counts are
+   logged (``flash, 1/1 tiles`` at seq 512). Every loss must be finite, and the first
    elastic step must match ``dsgd_train_step`` on the same state and batch
    (the plain reference; bit-exact on the CPU) within ``PARITY_REL``.
 
@@ -376,6 +377,7 @@ def training_phase() -> None:
     step_s = [h["step_s"] for h in hist]
     stats = dev.memory_stats() or {}
     say("train", arch=ARCH, workers=n, batch=b, seq=SEQ, steps=STEPS,
+        attention=out["attention"],
         topology=out["topology"], r_asym=out["r_asym"], losses=losses,
         step_s=step_s, warm_step_s=statistics.median(step_s[1:]),
         peak_bytes_in_use=stats.get("peak_bytes_in_use"))
@@ -496,6 +498,7 @@ def sharded_vs_stacked(ndev: int) -> None:
     from repro.dsgd import (make_elastic_sharded_train_step,
                             schedule_from_topology, schedule_weight_arrays)
     from repro.dsgd.elastic import make_elastic_train_step
+    from repro.launch import train
     from repro.launch.mesh import make_mesh
 
     cfg = published_config()
@@ -537,6 +540,7 @@ def sharded_vs_stacked(ndev: int) -> None:
             del out
 
     say("sharded_elastic", arch=ARCH, workers=n, batch=b, seq=SEQ,
+        attention=train.attention_path(cfg, SEQ),
         topology=topo.name, r_asym=topo.r_asym(), rounds=sched.rounds,
         loss=sharded[1.0][2], stacked_loss=stacked[1.0][2],
         cold_s=times[0], warm_s=times[1], stacked_two_steps_s=t_stacked)

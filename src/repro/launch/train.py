@@ -57,8 +57,10 @@ from repro.dsgd import (
 )
 from repro.dsgd.dynamic import cycle_weight_matrices, round_robin_schedules
 from repro.dsgd.trainer import _consensus_error, _loss_fn
+from repro.kernels.flash_attention import ops as flash
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import topology_for
+from repro.models.transformer import layer_windows
 from repro.optim import apply_updates, make_optimizer, warmup_cosine
 
 
@@ -99,9 +101,21 @@ def _dynamic_step(cfg, topo, opt_update):
     return _dyn_step, len(Ws)
 
 
+def attention_path(cfg, seq: int) -> str:
+    """The training step's attention path on this backend and its tile
+    counts, e.g. ``flash, 10/16 tiles``; ``none`` for attention-free
+    models."""
+    if cfg.arch_type == "ssm":
+        return "none"
+    S = seq + (cfg.frontend_tokens if cfg.arch_type == "vlm" else 0)
+    return flash.describe(S, cfg.resolved_head_dim, cfg.num_heads // cfg.num_kv_heads,
+                          windows=np.asarray(layer_windows(cfg)).tolist())
+
+
 def main(argv: list[str] | None = None) -> dict:
     """Run the training loop; returns the run record (also written to
-    ``--json-out``): config, topology, per-logged-step metrics with the
+    ``--json-out``): config, topology, the attention path
+    (:func:`attention_path`), per-logged-step metrics with the
     step's wall time ``step_s`` (elastic: and the round's host split
     ``plan_s``/``dispatch_s``/``sync_s``/``reopt_s``), and the elastic
     trail."""
@@ -226,8 +240,10 @@ def main(argv: list[str] | None = None) -> dict:
             mgr.save(state, step_label,
                      extra=runtime.to_extras(es) if args.elastic else None)
 
+    attention = attention_path(cfg, args.seq)
     print(f"arch={cfg.name} workers={n} sync={sync_desc} "
-          f"modelled t_iter={iter_time * 1e3:.2f}ms (paper Eq. 34)")
+          f"modelled t_iter={iter_time * 1e3:.2f}ms (paper Eq. 34) "
+          f"attention={attention}")
     history = []
     elastic_log = []
     t0 = time.time()
@@ -262,7 +278,7 @@ def main(argv: list[str] | None = None) -> dict:
         if s and s % args.ckpt_every == 0:
             save(int(state.step))
     save(int(state.step) if args.steps > start else args.steps)
-    out = {"config": vars(args), "topology": topo.name,
+    out = {"config": vars(args), "topology": topo.name, "attention": attention,
            "r_asym": topo.r_asym() if len(topo.edges) else None,
            "history": history}
     if args.elastic:

@@ -10,6 +10,7 @@ Shapes:
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -143,6 +144,22 @@ def attend_chunked(q, k, v, window, attn_softcap: float = 0.0, *, chunk: int = 1
     return out.reshape(B, S, Hq, hd).astype(q.dtype)
 
 
+def _attend_causal(q, k, v, window, attn_softcap: float):
+    """Causal (sliding-window where ``window`` > 0) attention of a training or
+    prefill sequence. Lowered for a TPU, where the shape fits its tiles, it
+    is the Pallas flash kernel; everywhere else ``attend_chunked``."""
+    from repro.kernels.flash_attention import ops as flash
+
+    chunked = partial(attend_chunked, attn_softcap=attn_softcap)
+    S, Hq, hd = q.shape[1:]
+    if flash.tile_size(S, hd, Hq // k.shape[2]) is None:
+        return chunked(q, k, v, window)
+    return jax.lax.platform_dependent(
+        q, k, v, window, default=chunked,
+        tpu=partial(flash.flash_attention, attn_softcap=attn_softcap,
+                    interpret=False))
+
+
 def attn_forward(params, x, cfg, *, window=0, positions=None, cache: KVCache | None = None,
                  chunked: bool = True):
     """Full-sequence forward (train / prefill). Returns (out, new_cache)."""
@@ -154,7 +171,7 @@ def attn_forward(params, x, cfg, *, window=0, positions=None, cache: KVCache | N
     k = apply_rope(k, positions, cfg.rope_theta)
     with obs.scope("attention"):
         if chunked and S > 128:
-            out = attend_chunked(q, k, v, window, cfg.attn_logit_softcap)
+            out = _attend_causal(q, k, v, window, cfg.attn_logit_softcap)
         else:
             mask = _causal_mask(S, window)
             out = attend_full(q, k, v, mask, cfg.attn_logit_softcap)

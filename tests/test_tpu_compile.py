@@ -26,7 +26,8 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
+    """A described v5e:2x2 (four chips)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -44,8 +45,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _sds(sharding, shape, dtype):
@@ -183,3 +189,79 @@ def test_admm_chunk_driver_compiles_for_v5e(one_chip):
     assert "while" in hlo
     assert all("X64Combine" in ln or "parameter(" in ln
                for ln in _f64_lines(hlo) if "= f64[" in ln)
+
+
+def _flash_kernels(hlo: str) -> set[str]:
+    """Names of the flash attention Mosaic calls in compiled HLO text."""
+    return {m for ln in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in ln
+            for m in re.findall(r"%(flash_attention_(?:fwd|bwd))", ln)}
+
+
+def _score_blocks(hlo: str) -> list[str]:
+    """``attend_chunked``'s float32 score blocks at smollm-135m's training
+    shape, (..., S = 2048, Hkv = 3, g = 3, chunk = 1024): stacked
+    ``f32[4,1,2048,3,3,1024]``, sharded ``f32[1,2048,3,3,1024]``."""
+    return re.findall(r"f32\[[\d,]*2048,3,3,1024\]", hlo)
+
+
+def _smollm_stacked_step(one_chip, n: int, b: int, seq: int):
+    from repro.configs import get_arch
+    from repro.dsgd import init_dsgd_state
+    from repro.dsgd.elastic import make_elastic_train_step
+    from repro.optim import make_optimizer, warmup_cosine
+
+    cfg = replace(get_arch("smollm-135m"), num_layers=2)
+    opt_init, opt_update = make_optimizer("sgd", warmup_cosine(0.05, 1, 5))
+    state = _abstract(one_chip, jax.eval_shape(
+        lambda: init_dsgd_state(jax.random.PRNGKey(0), cfg, n, opt_init)))
+    tok = _sds(one_chip, (n, b, seq), jnp.int32)
+    f32 = partial(_sds, one_chip, dtype=jnp.float32)
+    return make_elastic_train_step(cfg, opt_update).lower(
+        state, {"tokens": tok, "labels": tok}, f32((n, n)), f32((n,)),
+        f32((n, n)), f32((n,))).compile()
+
+
+def test_stacked_step_at_the_cells_shape_runs_flash_attention(one_chip):
+    """The stacked training cell's step (smollm-135m at published widths, 2
+    of its 30 layers, 4 workers x 1 x 2048): attention is the Pallas flash
+    kernel, forward (twice: the layer remat) and backward, and the float32
+    (workers, 1, S, Hkv, g, 1024) score blocks of ``attend_chunked`` are
+    gone; still no f64 although the solver's x64 switch is on."""
+    hlo = _smollm_stacked_step(one_chip, 4, 1, 2048).as_text()
+    assert _flash_kernels(hlo) == {"flash_attention_fwd", "flash_attention_bwd"}
+    assert _score_blocks(hlo) == []
+    assert _f64_lines(hlo) == []
+
+
+def test_sharded_step_at_the_cells_shape_runs_flash_attention(v5e):
+    """The sharded training cell's step: one smollm-135m worker (2 layers)
+    per chip of the described 2x2, 1 x 2048 tokens each, ppermute gossip
+    over the ring."""
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_arch
+    from repro.core.graph import Topology
+    from repro.dsgd import (DSGDState, init_dsgd_state,
+                            make_elastic_sharded_train_step, schedule_from_topology)
+    from repro.optim import make_optimizer, warmup_cosine
+
+    n, b, seq = 4, 1, 2048
+    cfg = replace(get_arch("smollm-135m"), num_layers=2)
+    opt_init, opt_update = make_optimizer("sgd", warmup_cosine(0.05, 1, 5))
+    mesh = Mesh(np.asarray(v5e.devices[:n]), ("data",), axis_types=(AxisType.Auto,))
+    shard, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    sched = schedule_from_topology(
+        Topology(n, [(i, (i + 1) % n) for i in range(n)], np.full(n, 1.0 / 3.0)))
+    state = jax.eval_shape(
+        lambda: init_dsgd_state(jax.random.PRNGKey(0), cfg, n, opt_init))
+    state = DSGDState(_abstract(shard, state.params), _abstract(shard, state.opt),
+                      _sds(rep, (), jnp.int32))
+    tok = _sds(shard, (n, b, seq), jnp.int32)
+    v = partial(_sds, rep, dtype=jnp.float32)
+    step = jax.jit(make_elastic_sharded_train_step(cfg, sched, opt_update, mesh))
+    with jax.set_mesh(mesh):
+        hlo = step.lower(state, {"tokens": tok, "labels": tok}, v((n,)), v((n,)),
+                         v((n,)), v((sched.rounds, n))).compile().as_text()
+    assert _flash_kernels(hlo) == {"flash_attention_fwd", "flash_attention_bwd"}
+    assert _score_blocks(hlo) == []
+    assert _f64_lines(hlo) == []
